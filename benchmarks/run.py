@@ -110,6 +110,8 @@ def main() -> None:
         sys.exit(2)
     if args.quick:
         os.environ["REPRO_BENCH_QUICK"] = "1"
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     print("name,us_per_call,derived")
     t0 = time.monotonic()

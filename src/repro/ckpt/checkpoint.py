@@ -34,7 +34,8 @@ from .io import get_bytes, put_bytes
 MB = 1024 * 1024
 
 
-def _flatten(tree):
+def flatten_state(tree) -> dict:
+    """``{"params/embed/table": leaf, ...}``: the manifest's leaf paths."""
     flat = jax.tree_util.tree_flatten_with_path(tree)[0]
     out = {}
     for keypath, leaf in flat:
@@ -49,20 +50,12 @@ def _leaf_bytes(leaf) -> bytes:
     return arr.tobytes()
 
 
-def _digest(leaf) -> str:
-    try:
-        from ..kernels.checksum.ops import checksum_digest
-        return checksum_digest(leaf, use_pallas=False)  # jnp path is fast
-    except Exception:
-        return digest_ref(_leaf_bytes(leaf))
-
-
 def save_checkpoint(state, connector: Connector, base: str, step: int,
                     credential: Credential | None = None,
                     bundle_threshold: int = 4 * MB,
                     verify: bool = True) -> dict:
     """Writes ``state`` under ``base/step_<n>/``.  Returns the manifest."""
-    leaves = _flatten(state)
+    leaves = flatten_state(state)
     session = connector.start(credential)
     tmp = f"{base}/step_{step}.tmp"
     final = f"{base}/step_{step}"
@@ -176,8 +169,8 @@ def restore_checkpoint(abstract_state, connector: Connector, base: str,
             return np.frombuffer(data, dtype=meta["dtype"]) \
                 .reshape(meta["shape"])
 
-        leaves = _flatten(abstract_state)
-        sh_leaves = _flatten(shardings) if shardings is not None else {}
+        leaves = flatten_state(abstract_state)
+        sh_leaves = flatten_state(shardings) if shardings is not None else {}
         restored = {}
         for path, spec in leaves.items():
             arr = load(path)

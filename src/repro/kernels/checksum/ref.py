@@ -1,8 +1,7 @@
-"""Pure-jnp / numpy oracle for the lanesum32 checksum."""
+"""numpy oracle for the lanesum32 checksum."""
 
 from __future__ import annotations
 
-import jax.numpy as jnp
 import numpy as np
 
 MOD = 1 << 32
@@ -23,15 +22,3 @@ def digest_ref(data: bytes) -> str:
     w = np.frombuffer(data + b"\0" * pad, dtype="<u4")
     a, b = lanesum32_ref(w)
     return f"{b:08x}{a:08x}"
-
-
-def jnp_lanesum32(words):
-    """jnp version used when the Pallas path is off.  Relies on int32
-    two's-complement wraparound (== arithmetic mod 2^32), same as the
-    kernel."""
-    w = words.astype(jnp.int32)
-    idx = jnp.arange(w.size, dtype=jnp.int32) + 1
-    a = jnp.sum(w)                # wraps mod 2^32
-    b = jnp.sum(w * idx)
-    to_u32 = lambda v: int(np.asarray(v, dtype=np.int64) & 0xFFFFFFFF)
-    return to_u32(a), to_u32(b)

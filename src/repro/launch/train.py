@@ -1,9 +1,10 @@
 """Training launcher: ``python -m repro.launch.train --arch <id> ...``
 
-Small-scale (CPU) end-to-end driver over the full stack: Connector-
-backed data, jitted train step, async checkpoints, optional third-party
-replication.  On a real pod, the same entry point runs per host with
-``--mesh single|multi`` and jax.distributed initialization.
+End-to-end driver over the full stack on one device: Connector-backed
+data, jitted train step, async checkpoints with lanesum32 manifests,
+optional third-party replication of each checkpoint.  Run it again with
+the same ``--ckpt-dir`` and more ``--steps`` and it resumes from the
+latest checkpoint (state and data cursor).
 """
 
 from __future__ import annotations
@@ -12,7 +13,8 @@ import argparse
 import os
 
 
-def main() -> None:
+def main(argv: list[str] | None = None):
+    """Returns ``(TrainResult, [TransferTask per replicated step])``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=100)
@@ -21,15 +23,19 @@ def main() -> None:
     ap.add_argument("--scaled-down", action="store_true", default=True)
     ap.add_argument("--full-size", dest="scaled_down", action="store_false")
     ap.add_argument("--ckpt-every", type=int, default=50)
-    ap.add_argument("--ckpt-dir", default="/tmp/repro-train")
+    ap.add_argument("--ckpt-dir", required=True,
+                    help="checkpoints, corpus and transfer markers; a "
+                         "later run with the same directory resumes")
     ap.add_argument("--replicate-to", default=None,
                     help="cloud provider id (s3|gcs|...) for third-party "
                          "checkpoint replication")
     ap.add_argument("--data-records", type=int, default=256)
     ap.add_argument("--lr", type=float, default=3e-3)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
-    import jax
+    from .compile_cache import use_compile_cache
+    use_compile_cache()
+
     from ..configs import get_config
     from ..connectors import PosixConnector, ObjectStoreConnector, make_cloud
     from ..core import Credential, CredentialStore, Endpoint, TransferService
@@ -55,17 +61,20 @@ def main() -> None:
 
     ckpt_mgr = CheckpointManager(store, "ckpt")
     replicator = None
+    replications = []
     if args.replicate_to:
         cloud = make_cloud(args.replicate_to)
         conn = ObjectStoreConnector(cloud, placement="cloud")
         creds = CredentialStore()
         creds.register("mirror", Credential(conn.credential_scheme, {}))
-        svc = TransferService(credential_store=creds)
+        svc = TransferService(credential_store=creds,
+                              marker_root=os.path.join(root, "markers"))
 
         def replicator(step):
             task = replicate_checkpoint(
                 svc, Endpoint(store, "ckpt"),
                 Endpoint(conn, "mirror", "mirror"), step, sync=True)
+            replications.append(task)
             print(f"  replicated step {step}: {task.status} "
                   f"({task.stats.bytes_done / 1e6:.1f} MB)")
 
@@ -81,6 +90,7 @@ def main() -> None:
           f"{result.final_loss:.4f}, {result.tokens_per_second:.0f} tok/s"
           + (f", restored from step {result.restored_from}"
              if result.restored_from else ""))
+    return result, replications
 
 
 if __name__ == "__main__":

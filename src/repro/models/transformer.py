@@ -424,18 +424,17 @@ def init_params(key, cfg: ArchConfig):
                                    dtype, scale)},
         "final_norm": rmsnorm_init(cfg.d_model, dtype),
     }
-    # stacked decoder blocks (scan axis = 0)
-    block_keys = jax.random.split(ks[1], cfg.n_blocks)
-    blocks = [block_init(k, cfg, dtype, cross_attention=cfg.is_encdec)
-              for k in block_keys]
-    params["blocks"] = jax.tree.map(lambda *xs: jnp.stack(xs), *blocks)
+    # stacked decoder blocks (scan axis = 0); vmap keeps one block's init
+    # in the program, not n_blocks copies of it
+    params["blocks"] = jax.vmap(
+        lambda k: block_init(k, cfg, dtype, cross_attention=cfg.is_encdec))(
+        jax.random.split(ks[1], cfg.n_blocks))
     if not cfg.tie_embeddings:
         params["lm_head"] = linear_init(ks[2], cfg.d_model, cfg.vocab_size,
                                         dtype, scale=scale)
     if cfg.is_encdec:
-        enc_keys = jax.random.split(ks[3], cfg.encdec.n_encoder_layers)
-        enc = [block_init(k, cfg, dtype) for k in enc_keys]
-        params["enc_blocks"] = jax.tree.map(lambda *xs: jnp.stack(xs), *enc)
+        params["enc_blocks"] = jax.vmap(lambda k: block_init(k, cfg, dtype))(
+            jax.random.split(ks[3], cfg.encdec.n_encoder_layers))
         params["enc_norm"] = rmsnorm_init(cfg.d_model, dtype)
     if cfg.vlm is not None:
         params["vision_proj"] = linear_init(ks[4], cfg.vlm.patch_dim,

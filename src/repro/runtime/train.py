@@ -9,6 +9,7 @@ data-iterator cursor) restore from the latest committed checkpoint.
 
 from __future__ import annotations
 
+import functools
 import json
 import time
 from dataclasses import dataclass, field
@@ -16,14 +17,15 @@ from dataclasses import dataclass, field
 import numpy as np
 
 import jax
-import jax.numpy as jnp
+from jax.sharding import NamedSharding
 
 from ..ckpt import CheckpointManager
 from ..ckpt.io import get_bytes, put_bytes
 from ..core.errors import NotFound
 from ..models.registry import ModelApi
 from ..optim import OptimizerConfig
-from .steps import make_train_state, make_train_step
+from ..sharding.rules import batch_spec
+from .steps import abstract_train_state, make_train_state, make_train_step
 
 
 @dataclass
@@ -49,6 +51,9 @@ def run_training(api: ModelApi, opt_cfg: OptimizerConfig,
                  loop_cfg: TrainLoopConfig, data_iter,
                  ckpt_mgr: CheckpointManager | None = None,
                  replicator=None, mesh=None, state_shardings=None) -> TrainResult:
+    """``state_shardings`` places the train state on a mesh: it is built,
+    restored and stepped there.  ``mesh`` shards each batch over the
+    mesh's data axes; without it batches go to the default device."""
     train_step = make_train_step(api, opt_cfg)
     jit_kwargs = {}
     if state_shardings is not None:
@@ -56,16 +61,15 @@ def run_training(api: ModelApi, opt_cfg: OptimizerConfig,
                           out_shardings=(state_shardings, None))
     step_fn = jax.jit(train_step, donate_argnums=(0,), **jit_kwargs)
 
-    state = make_train_state(api, opt_cfg, jax.random.PRNGKey(loop_cfg.seed))
+    state = None
     start_step = 0
     restored_from = None
     if ckpt_mgr is not None:
-        abstract = jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), state)
-        restored, step = ckpt_mgr.restore_latest(abstract,
-                                                 shardings=state_shardings)
+        restored, step = ckpt_mgr.restore_latest(
+            abstract_train_state(api, opt_cfg), shardings=state_shardings)
         if restored is not None:
-            state = restored
+            state = (restored if state_shardings is not None
+                     else jax.device_put(restored))
             start_step = step
             restored_from = step
             # resume the data cursor
@@ -79,6 +83,10 @@ def run_training(api: ModelApi, opt_cfg: OptimizerConfig,
                     data_iter.restore(cursor)
             except NotFound:
                 pass
+    if state is None:
+        init = jax.jit(functools.partial(make_train_state, api, opt_cfg),
+                       out_shardings=state_shardings)
+        state = init(jax.random.PRNGKey(loop_cfg.seed))
 
     batches = (data_iter.prefetching_batches()
                if hasattr(data_iter, "prefetching_batches") else data_iter)
@@ -89,7 +97,8 @@ def run_training(api: ModelApi, opt_cfg: OptimizerConfig,
     for step in range(start_step + 1, loop_cfg.total_steps + 1):
         batch = next(batches) if hasattr(batches, "__next__") \
             else next(iter(batches))
-        batch = {k: jnp.asarray(v) for k, v in batch.items()}
+        batch = jax.device_put(batch, None if mesh is None else NamedSharding(
+            mesh, batch_spec(len(batch["tokens"]), mesh)))
         if loop_cfg.fail_at_step == step:
             raise RuntimeError(f"injected failure at step {step}")
         state, metrics = step_fn(state, batch)

@@ -170,6 +170,24 @@ def test_checksum_kernel_matches_bytes(shape, dtype):
     assert d_kernel == d_bytes == d_jnp
 
 
+@pytest.mark.parametrize("shape,dtype", [
+    ((1300, 128), np.float32),           # ragged last grid block
+    ((2048 * 128 + 17,), jnp.bfloat16),  # odd count: half-filled last word
+    ((4097 * 33 + 5,), np.int8),         # 1-byte elements, partial word
+], ids=["fp32", "bf16", "int8"])
+def test_checksum_word_view_matches_digest_ref(shape, dtype):
+    """The kernel reads elements, not words: each must land at its byte
+    position in the little-endian word stream that digest_ref hashes."""
+    rng = np.random.default_rng(7)
+    if dtype == np.int8:
+        x = rng.integers(-128, 128, shape, dtype=np.int8)
+    else:
+        x = (rng.standard_normal(shape) * 1000).astype(dtype)
+    want = digest_ref(x.tobytes())
+    assert checksum_digest(jnp.asarray(x)) == want
+    assert checksum_digest(jnp.asarray(x), use_pallas=False) == want
+
+
 @settings(max_examples=30, deadline=None)
 @given(st.binary(min_size=0, max_size=3000))
 def test_lanesum32_stream_matches_ref(data):
