@@ -261,8 +261,9 @@ def main():
     # calls that feed Clock.charged also land on the innermost open
     # span — so TaskStats.time_budget() decomposes a task's
     # actual_model_seconds into categories that sum EXACTLY (within
-    # float tolerance), even under chaos.  The tracer also exports a
-    # Perfetto-loadable timeline, and the manager streams registry
+    # float tolerance), even under chaos.  Each span is also stamped in
+    # host time, and the tracer exports that Perfetto-loadable
+    # timeline; the manager streams registry
     # snapshots on the StatusBus it already owns.
     with tempfile.TemporaryDirectory() as tmp:
         runner = ScenarioRunner(tmp)
@@ -287,7 +288,8 @@ def main():
         tracer = fleet.manager.tracer
         trace_path = os.path.join(tmp, "fleet_trace.json")
         n = tracer.export_chrome(trace_path)
-        print(f"  exported {n} spans as Chrome trace-event JSON -> "
+        print(f"  exported {n} spans as Chrome trace-event JSON on the "
+              f"host clock (model seconds in each span's args) -> "
               f"load in ui.perfetto.dev (export_jsonl gives the "
               f"canonical byte-stable form)")
         scrape = fleet.manager.scrape()

@@ -15,12 +15,19 @@ Design (DESIGN.md §2):
   re-sharded to whatever mesh the restoring job uses (elastic restart).
 * saves are atomic: objects land under ``<step>.tmp/`` and the manifest
   write is the commit point, then the directory is renamed.
+
+Each phase runs under a span of the tracer it is given (host time for the
+per-layer record): ``ckpt.snapshot`` (D2H), ``ckpt.serialize``,
+``ckpt.digest``, ``ckpt.put``, ``ckpt.verify-read``, ``ckpt.commit``,
+``ckpt.gc`` on a save; ``ckpt.read``, ``ckpt.digest``, ``ckpt.place``
+(H2D) on a restore.
 """
 
 from __future__ import annotations
 
 import json
 import threading
+from contextlib import contextmanager
 
 import numpy as np
 
@@ -29,6 +36,7 @@ import jax
 from ..core.connector import Connector, Credential, Session
 from ..core.errors import IntegrityError, NotFound
 from ..kernels.checksum.ref import digest_ref
+from ..obs.trace import NULL_TRACER, Tracer
 from .io import get_bytes, put_bytes
 
 MB = 1024 * 1024
@@ -53,7 +61,7 @@ def _leaf_bytes(leaf) -> bytes:
 def save_checkpoint(state, connector: Connector, base: str, step: int,
                     credential: Credential | None = None,
                     bundle_threshold: int = 4 * MB,
-                    verify: bool = True) -> dict:
+                    verify: bool = True, tracer: Tracer = NULL_TRACER) -> dict:
     """Writes ``state`` under ``base/step_<n>/``.  Returns the manifest."""
     leaves = flatten_state(state)
     session = connector.start(credential)
@@ -71,8 +79,9 @@ def save_checkpoint(state, connector: Connector, base: str, step: int,
             if not bundle:
                 return
             name = f"bundle_{bundle_idx}.bin"
-            blob = b"".join(b for _, b, _, _, _ in bundle)
-            put_bytes(connector, session, f"{tmp}/{name}", blob)
+            with tracer.span("ckpt.put"):
+                blob = b"".join(b for _, b, _, _, _ in bundle)
+                put_bytes(connector, session, f"{tmp}/{name}", blob)
             off = 0
             for path, data, dig, shape, dtype in bundle:
                 manifest["bundles"][path] = {
@@ -85,10 +94,12 @@ def save_checkpoint(state, connector: Connector, base: str, step: int,
             bundle_size = 0
 
         for path, leaf in sorted(leaves.items()):
-            data = _leaf_bytes(leaf)
-            dig = digest_ref(data)
-            shape = list(np.asarray(jax.device_get(leaf)).shape)
-            dtype = str(np.asarray(jax.device_get(leaf)).dtype)
+            with tracer.span("ckpt.serialize"):
+                data = _leaf_bytes(leaf)
+                arr = np.asarray(jax.device_get(leaf))
+                shape, dtype = list(arr.shape), str(arr.dtype)
+            with tracer.span("ckpt.digest"):
+                dig = digest_ref(data)
             if len(data) < bundle_threshold:
                 bundle.append((path, data, dig, shape, dtype))
                 bundle_size += len(data)
@@ -96,7 +107,8 @@ def save_checkpoint(state, connector: Connector, base: str, step: int,
                     flush_bundle()
                 continue
             obj = f"{tmp}/{path.replace('/', '.')}.bin"
-            put_bytes(connector, session, obj, data)
+            with tracer.span("ckpt.put"):
+                put_bytes(connector, session, obj, data)
             manifest["objects"][path] = {
                 "object": f"{path.replace('/', '.')}.bin",
                 "checksum": dig, "shape": shape, "dtype": dtype,
@@ -105,27 +117,34 @@ def save_checkpoint(state, connector: Connector, base: str, step: int,
 
         if verify:  # §7: re-read from storage and compare checksums
             for path, meta in manifest["objects"].items():
-                got = get_bytes(connector, session, f"{tmp}/{meta['object']}")
-                if digest_ref(got) != meta["checksum"]:
+                with tracer.span("ckpt.verify-read"):
+                    got = get_bytes(connector, session,
+                                    f"{tmp}/{meta['object']}")
+                with tracer.span("ckpt.digest"):
+                    ok = digest_ref(got) == meta["checksum"]
+                if not ok:
                     raise IntegrityError(f"post-write verify failed: {path}")
 
-        put_bytes(connector, session, f"{tmp}/manifest.json",
-                  json.dumps(manifest).encode())
-        connector.command(session, "rename", tmp, to=final)
-        # update the "latest" pointer last (atomic-ish commit marker)
-        put_bytes(connector, session, f"{base}/LATEST",
-                  str(step).encode())
+        with tracer.span("ckpt.commit"):
+            put_bytes(connector, session, f"{tmp}/manifest.json",
+                      json.dumps(manifest).encode())
+            connector.command(session, "rename", tmp, to=final)
+            # update the "latest" pointer last (atomic-ish commit marker)
+            put_bytes(connector, session, f"{base}/LATEST",
+                      str(step).encode())
         return manifest
     finally:
         connector.destroy(session)
 
 
 def latest_step(connector: Connector, base: str,
-                credential: Credential | None = None) -> int | None:
+                credential: Credential | None = None,
+                tracer: Tracer = NULL_TRACER) -> int | None:
     session = connector.start(credential)
     try:
         try:
-            return int(get_bytes(connector, session, f"{base}/LATEST"))
+            with tracer.span("ckpt.read"):
+                return int(get_bytes(connector, session, f"{base}/LATEST"))
         except NotFound:
             return None
     finally:
@@ -135,37 +154,45 @@ def latest_step(connector: Connector, base: str,
 def restore_checkpoint(abstract_state, connector: Connector, base: str,
                        step: int | None = None,
                        credential: Credential | None = None,
-                       shardings=None, verify: bool = True):
+                       shardings=None, verify: bool = True,
+                       tracer: Tracer = NULL_TRACER):
     """Restores into the structure of ``abstract_state``; if
     ``shardings`` (a matching pytree of NamedSharding) is given, arrays
     are placed sharded — on a *possibly different* mesh than the saver's
     (elastic restart)."""
     session = connector.start(credential)
     try:
-        if step is None:
-            step = int(get_bytes(connector, session, f"{base}/LATEST"))
-        root = f"{base}/step_{step}"
-        manifest = json.loads(get_bytes(connector, session,
-                                        f"{root}/manifest.json"))
+        with tracer.span("ckpt.read"):
+            if step is None:
+                step = int(get_bytes(connector, session, f"{base}/LATEST"))
+            root = f"{base}/step_{step}"
+            manifest = json.loads(get_bytes(connector, session,
+                                            f"{root}/manifest.json"))
         bundles_cache: dict[str, bytes] = {}
 
         def load(path: str) -> np.ndarray:
             if path in manifest["objects"]:
                 meta = manifest["objects"][path]
-                data = get_bytes(connector, session,
-                                 f"{root}/{meta['object']}")
+                with tracer.span("ckpt.read"):
+                    data = get_bytes(connector, session,
+                                     f"{root}/{meta['object']}")
             elif path in manifest["bundles"]:
                 meta = manifest["bundles"][path]
                 obj = meta["object"]
                 if obj not in bundles_cache:
-                    bundles_cache[obj] = get_bytes(connector, session,
-                                                   f"{root}/{obj}")
+                    with tracer.span("ckpt.read"):
+                        bundles_cache[obj] = get_bytes(connector, session,
+                                                       f"{root}/{obj}")
                 data = bundles_cache[obj][meta["offset"]:
                                           meta["offset"] + meta["length"]]
             else:
                 raise NotFound(f"checkpoint object for {path}")
-            if verify and digest_ref(data) != meta["checksum"]:
-                raise IntegrityError(f"checksum mismatch restoring {path}")
+            if verify:
+                with tracer.span("ckpt.digest"):
+                    ok = digest_ref(data) == meta["checksum"]
+                if not ok:
+                    raise IntegrityError(
+                        f"checksum mismatch restoring {path}")
             return np.frombuffer(data, dtype=meta["dtype"]) \
                 .reshape(meta["shape"])
 
@@ -175,7 +202,8 @@ def restore_checkpoint(abstract_state, connector: Connector, base: str,
         for path, spec in leaves.items():
             arr = load(path)
             if sh_leaves.get(path) is not None:
-                arr = jax.device_put(arr, sh_leaves[path])
+                with tracer.span("ckpt.place"):
+                    arr = jax.device_put(arr, sh_leaves[path])
             restored[path] = arr
 
         flat = jax.tree_util.tree_flatten_with_path(abstract_state)
@@ -197,14 +225,21 @@ class CheckpointManager:
     background thread streams objects through the Connector —
     fire-and-forget, same as the paper's managed transfers.  ``retain``
     old checkpoints are garbage-collected.
+
+    Every save and restore runs under one trace of ``tracer`` (its own
+    enabled ``Tracer`` by default), ``save-<step>`` or
+    ``restore-<step>``, on each thread it uses; the trace's model-time
+    tally is forgotten when the operation ends.
     """
 
     def __init__(self, connector: Connector, base: str,
-                 credential: Credential | None = None, retain: int = 3):
+                 credential: Credential | None = None, retain: int = 3,
+                 tracer: Tracer | None = None):
         self.connector = connector
         self.base = base
         self.credential = credential
         self.retain = retain
+        self.tracer = tracer or Tracer()
         self._thread: threading.Thread | None = None
         self._error: Exception | None = None
         self._saved_steps: list[int] = []
@@ -219,15 +254,20 @@ class CheckpointManager:
 
     def save_async(self, state, step: int):
         self.wait()
-        host_state = jax.tree.map(lambda x: np.asarray(jax.device_get(x)),
-                                  state)
+        trace = f"save-{step}"
+        with self._traced(trace), self.tracer.span("ckpt.snapshot"):
+            host_state = jax.tree.map(
+                lambda x: np.asarray(jax.device_get(x)), state)
 
         def work():
             try:
-                save_checkpoint(host_state, self.connector, self.base, step,
-                                credential=self.credential)
-                self._saved_steps.append(step)
-                self._gc()
+                with self._traced(trace):
+                    save_checkpoint(host_state, self.connector, self.base,
+                                    step, credential=self.credential,
+                                    tracer=self.tracer)
+                    self._saved_steps.append(step)
+                    with self.tracer.span("ckpt.gc"):
+                        self._gc()
             except Exception as e:  # surfaced on next wait()
                 self._error = e
 
@@ -247,9 +287,24 @@ class CheckpointManager:
                 self.connector.destroy(session)
 
     def restore_latest(self, abstract_state, shardings=None):
-        step = latest_step(self.connector, self.base, self.credential)
+        with self._traced("restore-latest"):
+            step = latest_step(self.connector, self.base, self.credential,
+                               tracer=self.tracer)
         if step is None:
             return None, None
-        return restore_checkpoint(abstract_state, self.connector, self.base,
-                                  step, credential=self.credential,
-                                  shardings=shardings)
+        with self._traced(f"restore-{step}"):
+            return restore_checkpoint(abstract_state, self.connector,
+                                      self.base, step,
+                                      credential=self.credential,
+                                      shardings=shardings,
+                                      tracer=self.tracer)
+
+    @contextmanager
+    def _traced(self, trace: str):
+        """This thread bound to ``trace`` of the manager's tracer while
+        entered; the trace's model-time tally is forgotten on exit."""
+        try:
+            with self.tracer.bind(trace, trace):
+                yield
+        finally:
+            self.tracer.forget(trace)

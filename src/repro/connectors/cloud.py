@@ -29,9 +29,15 @@ from ..core.clock import (Clock, DEFAULT_CLOCK, Link, TokenBucket,
 from ..core.connector import AppChannel, Connector, Credential, Session, StatInfo
 from ..core.errors import AuthError, FaultInjected, NotFound, RateLimitError
 from ..core.faults import FaultSchedule
+from ..obs.trace import spanned
 from .memory import BlobDict
 
 MB = 1024 * 1024
+
+#: each ObjectStoreConnector call runs under a ``connector.io`` span of
+#: the tracer bound on the calling thread (the category of the span
+#: around it)
+_io = spanned("connector.io")
 
 
 @dataclass(frozen=True)
@@ -295,6 +301,7 @@ class ObjectStoreConnector(Connector):
         self.access_link = (lan_link(self.clock) if placement == "cloud"
                             else wan_link(self.clock))
 
+    @_io
     def checksum(self, session: Session, path: str, algorithm: str) -> str:
         if self.server_checksum:
             session.check()
@@ -316,10 +323,12 @@ class ObjectStoreConnector(Connector):
         return path.strip("/")
 
     # -- metadata ----------------------------------------------------------
+    @_io
     def stat(self, session: Session, path: str) -> StatInfo:
         session.check()
         return self.storage.api_stat(self._key(path), self.access_link)
 
+    @_io
     def listdir(self, session: Session, path: str):
         session.check()
         objs, dirs = self.storage.api_list(self._key(path), self.access_link)
@@ -328,6 +337,7 @@ class ObjectStoreConnector(Connector):
         out += [StatInfo(name=d, size=0, mtime=0.0, is_dir=True) for d in dirs]
         return out
 
+    @_io
     def command(self, session: Session, op: str, path: str, **kw) -> None:
         session.check()
         key = self._key(path)
@@ -361,6 +371,7 @@ class ObjectStoreConnector(Connector):
         self.storage._admit("copy", 1, self.access_link, key=key)
 
     # -- data ----------------------------------------------------------------
+    @_io
     def send(self, session: Session, path: str, channel: AppChannel) -> None:
         session.check()
         key = self._key(path)
@@ -387,6 +398,7 @@ class ObjectStoreConnector(Connector):
         if err:
             raise err[0]
 
+    @_io
     def recv(self, session: Session, path: str, channel: AppChannel) -> None:
         session.check()
         key = self._key(path)
@@ -444,6 +456,7 @@ class ObjectStoreConnector(Connector):
         return ApiPipeline(self.storage, self.access_link,
                            depth=min(self.pipeline_depth, max(1, n_files)))
 
+    @_io
     def send_batch(self, session: Session, paths, channel_factory) -> None:
         """Native batch Send: per-object stat + GET issued through one
         request pipeline (amortized admission), files spread over the
@@ -475,6 +488,7 @@ class ObjectStoreConnector(Connector):
         self._dispatch_batch(session, paths, channel_factory, one,
                              pool_size=self.pipeline_depth)
 
+    @_io
     def recv_batch(self, session: Session, paths, channel_factory) -> None:
         """Native batch Recv: grouped small objects go up as pipelined
         single-shot PUTs (no per-object multipart complete); holey

@@ -18,6 +18,11 @@ import threading
 from ..core.clock import bind_charge_owner
 from ..core.connector import AppChannel, ByteRange, Connector, Session, StatInfo
 from ..core.errors import NotFound, PermanentError
+from ..obs.trace import spanned
+
+#: each storage call runs under a ``connector.io`` span of the tracer
+#: bound on the calling thread (the category of the span around it)
+_io = spanned("connector.io")
 
 
 class PosixConnector(Connector):
@@ -39,6 +44,7 @@ class PosixConnector(Connector):
         return os.path.relpath(abspath, self.root)
 
     # -- metadata --------------------------------------------------------
+    @_io
     def stat(self, session: Session, path: str) -> StatInfo:
         session.check()
         p = self._abs(path)
@@ -57,6 +63,7 @@ class PosixConnector(Connector):
             gid=st.st_gid,
         )
 
+    @_io
     def listdir(self, session: Session, path: str):
         session.check()
         p = self._abs(path)
@@ -78,6 +85,7 @@ class PosixConnector(Connector):
                 )
         return out
 
+    @_io
     def command(self, session: Session, op: str, path: str, **kw) -> None:
         session.check()
         p = self._abs(path)
@@ -134,6 +142,7 @@ class PosixConnector(Connector):
                 channel.bytes_written(rng.offset + done, len(data))
                 done += len(data)
 
+    @_io
     def send(self, session: Session, path: str, channel: AppChannel) -> None:
         session.check()
         p = self._abs(path)
@@ -171,6 +180,7 @@ class PosixConnector(Connector):
             pass
         return open(p, "r+b")
 
+    @_io
     def recv(self, session: Session, path: str, channel: AppChannel) -> None:
         session.check()
         bs = channel.get_blocksize()
@@ -202,7 +212,10 @@ class PosixConnector(Connector):
         if err:
             raise err[0]
 
+    checksum = _io(Connector.checksum)
+
     # -- bulk data plane --------------------------------------------------
+    @_io
     def send_batch(self, session: Session, paths, channel_factory) -> None:
         """Native batch Send: one single-stream task per file on the
         session's shared pool (threads reused across files/attempts);
@@ -225,6 +238,7 @@ class PosixConnector(Connector):
 
         self._dispatch_batch(session, paths, channel_factory, one)
 
+    @_io
     def recv_batch(self, session: Session, paths, channel_factory) -> None:
         """Native batch Recv — single stream + private handle per file,
         no cross-stream handle lock needed."""

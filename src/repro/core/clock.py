@@ -38,12 +38,14 @@ def _env_scale() -> float:
 # deadline can never fire against a wedged real thread — harnesses and
 # cv-slicing loops that bound REAL threads (scenario kill windows, the
 # coordinator's caller-facing wait_all timeout) genuinely need the wall
-# clock.  They get it from these two helpers and nowhere else: the
+# clock.  They get it from these helpers and nowhere else: the
 # contract linter (rule R001, ``python -m repro.lint``) bans direct
 # ``time.time/monotonic/sleep`` outside this module, so every wall
-# read in the stack is greppable as wall_now/wall_sleep and auditable
-# here.  Neither helper charges model time; code that does model-visible
-# waiting must go through ``Clock.sleep`` under a bound charge owner.
+# read in the stack is greppable as wall_now/wall_sleep/host_now and
+# auditable here.  ``host_now`` is the timer of the timed path (span
+# host stamps, real elapsed-time stats).  No helper charges model time;
+# code that does model-visible waiting must go through ``Clock.sleep``
+# under a bound charge owner.
 
 
 def wall_now() -> float:
@@ -55,6 +57,15 @@ def wall_now() -> float:
 def wall_sleep(seconds: float) -> None:
     """Real sleep — for harness polls between wall_now() checks."""
     time.sleep(seconds)
+
+
+#: ``host_now()``: host seconds on ``time.perf_counter`` — the one
+#: real-time stamp of the timed path: span host stamps,
+#: ``TaskStats.wall_seconds``, the tuner's wall rate.  It is the clock a
+#: JAX profiler trace is mapped onto, so spans line up with the device's
+#: operations.  Never for model-visible state.  The function itself, not
+#: a wrapper: every span reads it twice.
+host_now = time.perf_counter
 
 
 # --------------------------------------------------------------------------

@@ -58,7 +58,7 @@ from __future__ import annotations
 import threading
 from dataclasses import dataclass, field
 
-from .clock import Clock, DEFAULT_CLOCK
+from .clock import Clock, DEFAULT_CLOCK, host_now
 from .errors import EndpointUnavailable
 from ..obs.trace import NULL_TRACER
 
@@ -94,7 +94,8 @@ class _EpState:
     """Per-endpoint mutable state; guarded by the registry lock."""
 
     __slots__ = ("ep", "state", "ewma", "samples", "opened_at", "probing",
-                 "probe_ok", "tokens", "vlast", "entered_at")
+                 "probe_ok", "tokens", "vlast", "entered_at",
+                 "entered_host")
 
     def __init__(self, ep: str, capacity: float):
         self.ep = ep
@@ -107,6 +108,7 @@ class _EpState:
         self.tokens = capacity
         self.vlast = 0.0
         self.entered_at = 0.0  # model time the current breaker state began
+        self.entered_host = host_now()  # ... and its host time
 
 
 class _Ticket:
@@ -171,9 +173,12 @@ class EndpointHealth:
         self.transitions.append((now, s.ep, s.state, new))
         # the window just closed (e.g. the whole "open" cooldown) becomes
         # a retroactive span: visible in trace exports, charges nothing
+        host = host_now()
         self.tracer.record(f"breaker-{s.state}", "health",
-                           s.entered_at, now, endpoint=s.ep, to=new)
+                           s.entered_at, now, endpoint=s.ep, to=new,
+                           host_t0=s.entered_host, host_t1=host)
         s.entered_at = now
+        s.entered_host = host
         s.state = new
 
     def _deny(self, ep: str, retry_after: float, reason: str,

@@ -18,6 +18,8 @@ from __future__ import annotations
 import hashlib
 import zlib
 
+from ..obs.trace import thread_span
+
 
 class _Crc32:
     name = "crc32"
@@ -108,7 +110,25 @@ class _LaneSum32:
         return f"{b:08x}{a:08x}"
 
 
-def hasher(algorithm: str):
+class _Spanned:
+    """A hasher whose ``update`` and ``hexdigest`` each run under an
+    ``integrity.hash`` span of the tracer bound on the calling thread."""
+
+    __slots__ = ("_h",)
+
+    def __init__(self, h):
+        self._h = h
+
+    def update(self, data: bytes) -> None:
+        with thread_span("integrity.hash", "integrity"):
+            self._h.update(data)
+
+    def hexdigest(self) -> str:
+        with thread_span("integrity.hash", "integrity"):
+            return self._h.hexdigest()
+
+
+def _make(algorithm: str):
     if algorithm in ("sha256", "md5", "sha1"):
         return hashlib.new(algorithm)
     if algorithm == "crc32":
@@ -118,6 +138,10 @@ def hasher(algorithm: str):
     if algorithm == "lanesum32":
         return _LaneSum32()
     raise ValueError(f"unknown checksum algorithm: {algorithm}")
+
+
+def hasher(algorithm: str):
+    return _Spanned(_make(algorithm))
 
 
 def checksum_bytes(data: bytes, algorithm: str = "sha256") -> str:

@@ -49,14 +49,18 @@ from collections import deque
 from contextlib import contextmanager
 from dataclasses import asdict, dataclass, field, replace
 
-from .clock import charge_to
+from .clock import charge_to, host_now
 from .connector import Session, iter_files
 from .perfmodel import Advisor, Route, fit_perf_model
 from .transfer import (Endpoint, TransferOptions, TransferService,
                        TransferTask)
-from ..obs import MetricsRegistry, Tracer
-from ..obs.trace import NULL_TRACER
+from ..obs.metrics import MetricsRegistry
+from ..obs.trace import NULL_TRACER, Tracer
 from ..svc import StatusBus
+
+#: a run that ends in one of these completes its task
+_FINISHED = (TransferTask.SUCCEEDED, TransferTask.FAILED,
+             TransferTask.CANCELLED)
 
 
 # --------------------------------------------------------------------------
@@ -205,8 +209,12 @@ class _Submission:
     #: status PAUSED, re-queue instead of filing into the paused set
     resume_pending: bool = False
     #: model time this submission (last) entered the ready queue — the
-    #: start of the retroactive "queue-wait" span recorded at dispatch
+    #: start of the retroactive "queue-wait" span — and the model time
+    #: it was dispatched; ``enqueued_host`` is the same start in host
+    #: time (``host_now``), for the span's host stamps
     enqueued_at: float = 0.0
+    dispatched_at: float = 0.0
+    enqueued_host: float = 0.0
     #: seq of this submission's live heap entry, or None when it holds
     #: none (running / paused / cancelled).  A heap item is a tombstone
     #: unless its seq matches — that is what lets pause/cancel dequeue
@@ -493,6 +501,7 @@ class TransferManager:
         heapq.heappush(heap, (sub.priority, sub.seq, sub))
         sub.queued_seq = sub.seq
         sub.enqueued_at = self.service.clock.virtual_elapsed
+        sub.enqueued_host = host_now()
         if sub.tenant not in self._rr:
             self._rr.append(sub.tenant)
         self._queued[sub.task.task_id] = sub
@@ -643,12 +652,11 @@ class TransferManager:
         by_tenant = self.metrics.dispatches_by_tenant
         by_tenant[sub.tenant] = by_tenant.get(sub.tenant, 0) + 1
         self.metrics.dispatch_log.append((sub.tenant, tid))
-        # queue time was waited out, not slept through: a retroactive
-        # span (visible in exports, charges nothing) + a histogram point
+        # queue time was waited out, not slept through: a histogram
+        # point here, a retroactive span (visible in exports, charges
+        # nothing) once the worker runs
         now = self.service.clock.virtual_elapsed
-        self.tracer.record("queue-wait", "queue", sub.enqueued_at, now,
-                           trace_id=sub.task.trace_id, task_id=tid,
-                           tenant=sub.tenant)
+        sub.dispatched_at = now
         self._queue_wait.observe(max(0.0, now - sub.enqueued_at),
                                  site=self.site_id, tenant=sub.tenant)
         self._touch_locked("dispatched", sub.task, tenant=sub.tenant)
@@ -687,6 +695,13 @@ class TransferManager:
             self.sessions.release(src, s_src)
 
     def _run_one(self, sub: _Submission) -> None:
+        # the queue wait ends on the worker's first line, so in host
+        # time it includes the thread's start
+        started = host_now()
+        self.tracer.record("queue-wait", "queue", sub.enqueued_at,
+                           sub.dispatched_at, trace_id=sub.task.trace_id,
+                           task_id=sub.task.task_id, tenant=sub.tenant,
+                           host_t0=sub.enqueued_host, host_t1=started)
         # per-task charge accounting: the run attributes every model-time
         # charge (across all the threads it fans out into) to this task,
         # so the delta is exact even with max_workers > 1 — concurrent
@@ -712,6 +727,23 @@ class TransferManager:
 
     def _on_done(self, sub: _Submission, model_seconds: float,
                  span_seconds: dict | None = None) -> None:
+        task = sub.task
+        try:
+            refit_due = self._account_run(sub, model_seconds, span_seconds)
+        finally:
+            # only now, its accounting whole, does a finished task
+            # complete: whoever waits on it reads settled stats and
+            # metrics, and is released even when that accounting raised
+            if task.status in _FINISHED:
+                task._finish(task.status)
+        if refit_due is not None:
+            self._auto_refit(refit_due)
+        self._pump()
+
+    def _account_run(self, sub: _Submission, model_seconds: float,
+                     span_seconds: dict | None) -> str | None:
+        """File one finished run of ``sub``: its charges, its new state,
+        the events and metrics; returns the route due a refit, if any."""
         task = sub.task
         refit_due: str | None = None
         with self._lock:
@@ -789,9 +821,7 @@ class TransferManager:
                     self.bus.publish("metrics",
                                      data=self.registry.snapshot(),
                                      site_id=self.site_id)
-        if refit_due is not None:
-            self._auto_refit(refit_due)
-        self._pump()
+        return refit_due
 
     def _auto_refit(self, route_name: str) -> None:
         """One turn of the closed loop: refit the route from its recent

@@ -49,9 +49,9 @@ from collections import deque
 from contextlib import ExitStack, contextmanager
 from dataclasses import dataclass, field
 
-from ..obs.trace import NULL_TRACER
+from ..obs.trace import NULL_TRACER, thread_span
 from .clock import (Clock, DEFAULT_CLOCK, Link, bind_charge_owner, charge_to,
-                    loopback)
+                    host_now, loopback)
 from .connector import (AppChannel, ByteRange, Connector, Credential, Session,
                         iter_files)
 from .errors import (EndpointUnavailable, IntegrityError, PermanentError,
@@ -421,7 +421,10 @@ class MarkerStore:
     whole task state on every file — and the journal is folded into the
     snapshot every ``compact_every`` records.  ``load``/``save``/
     ``append``/``clear`` all take the store lock, so a resume racing an
-    in-flight flush can never observe a torn state.
+    in-flight flush can never observe a torn state; each runs under a
+    ``marker`` span of the tracer bound on the calling thread (the
+    task's binding of the service's tracer), the wait for the lock
+    included.
     """
 
     def __init__(self, root: str, compact_every: int = 256):
@@ -438,7 +441,7 @@ class MarkerStore:
         return os.path.join(self.root, f"{task_id}.marker.jsonl")
 
     def load(self, task_id: str) -> dict:
-        with self._lock:
+        with thread_span("marker", "overhead", op="load"), self._lock:
             return self._load_locked(task_id)
 
     def _load_locked(self, task_id: str) -> dict:
@@ -476,7 +479,7 @@ class MarkerStore:
 
     def save(self, task_id: str, state: dict) -> None:
         """Full snapshot: rewrites the base and truncates the journal."""
-        with self._lock:
+        with thread_span("marker", "overhead", op="save"), self._lock:
             self._save_locked(task_id, state)
 
     def _save_locked(self, task_id: str, state: dict) -> None:
@@ -492,7 +495,7 @@ class MarkerStore:
 
     def append(self, task_id: str, path: str, entry: dict) -> None:
         """Record one file's progress — O(record), not O(task state)."""
-        with self._lock:
+        with thread_span("marker", "overhead", op="append"), self._lock:
             with open(self._journal_path(task_id), "a") as f:
                 f.write(json.dumps({"file": path, **entry}) + "\n")
             n = self._journal_counts.get(task_id, 0) + 1
@@ -501,7 +504,7 @@ class MarkerStore:
                 self._save_locked(task_id, self._load_locked(task_id))
 
     def clear(self, task_id: str) -> None:
-        with self._lock:
+        with thread_span("marker", "overhead", op="clear"), self._lock:
             for p in (self._path(task_id), self._journal_path(task_id)):
                 if os.path.exists(p):
                     os.remove(p)
@@ -1114,13 +1117,24 @@ class TransferService:
         finally:
             src.connector.destroy(s_src)
 
+    def _bind(self, task: TransferTask):
+        """The task's trace binding of the service's tracer."""
+        return self.tracer.bind(task.trace_id or f"trace-{task.task_id}",
+                                task.task_id)
+
+    def _clear_markers(self, task: TransferTask) -> None:
+        with self._bind(task):
+            self.markers.clear(task.task_id)
+
     def _run(self, task: TransferTask, src: Endpoint, dst: Endpoint,
              opt: TransferOptions, session_scope=None) -> None:
         """Execute (or re-execute, after a pause) one task.  Progress
         counters are recomputed from restart markers each run, so a
         resumed task's stats stay consistent instead of double-counting
-        the bytes that landed before the pause."""
-        t_start = time.monotonic()  # lint: disable=R001(wall_seconds stat is real elapsed time by design — model time lives in model_seconds)
+        the bytes that landed before the pause.  Sets the final status;
+        the manager completes the task (``_finish``) once its own
+        accounting of the run is whole."""
+        t_start = host_now()
         task._idle.clear()
         task.status = TransferTask.ACTIVE
         with task._lock:
@@ -1136,10 +1150,7 @@ class TransferService:
             # the run fans out into (see clock.charge_to /
             # bind_charge_owner); the tracer binding rides the same
             # thread-local slot so spans attach to this task everywhere
-            with charge_to(task.task_id), \
-                    self.tracer.bind(task.trace_id
-                                     or f"trace-{task.task_id}",
-                                     task.task_id), \
+            with charge_to(task.task_id), self._bind(task), \
                     ExitStack() as stack:
                 # third-party coordination / endpoint activation (§5.4)
                 with self.tracer.span("startup", "startup"):
@@ -1149,14 +1160,14 @@ class TransferService:
                 self._execute(task, src, dst, s_src, s_dst, opt)
         except Exception as e:
             task.log(f"FATAL {type(e).__name__}: {e}")
-            task.stats.wall_seconds += time.monotonic() - t_start  # lint: disable=R001(wall_seconds stat is real elapsed time by design)
-            task._finish(TransferTask.FAILED)
+            task.stats.wall_seconds += host_now() - t_start
+            task.status = TransferTask.FAILED
             return
-        task.stats.wall_seconds += time.monotonic() - t_start  # lint: disable=R001(wall_seconds stat is real elapsed time by design)
+        task.stats.wall_seconds += host_now() - t_start
         if task._cancel_req.is_set():
-            self.markers.clear(task.task_id)
+            self._clear_markers(task)
             task.log("cancelled")
-            task._finish(TransferTask.CANCELLED)
+            task.status = TransferTask.CANCELLED
             return
         if task._pause_req.is_set():
             incomplete = (task.stats.files_done + task.stats.files_failed
@@ -1172,8 +1183,8 @@ class TransferService:
             task._pause_req.clear()
         ok = task.stats.files_failed == 0
         if ok:
-            self.markers.clear(task.task_id)
-        task._finish(TransferTask.SUCCEEDED if ok else TransferTask.FAILED)
+            self._clear_markers(task)
+        task.status = TransferTask.SUCCEEDED if ok else TransferTask.FAILED
 
     def _expand(self, src: Endpoint, dst: Endpoint, s_src: Session):
         """Directory expansion + per-file (src, dst, size, mtime) plan
@@ -1367,7 +1378,7 @@ class TransferService:
         best_rate = 0.0
         last_t = 0.0
         last_b = 0
-        last_w = time.monotonic()  # lint: disable=R001(tuner gain signal is wall rate under a scaled clock by design — see docstring)
+        last_w = host_now()
         while not stop.wait(0.002):
             with task._lock:
                 if not task._rate_samples:
@@ -1375,7 +1386,7 @@ class TransferService:
                 t, b = task._rate_samples[-1]
             if t - last_t < self.TUNE_WINDOW:
                 continue
-            now_w = time.monotonic()  # lint: disable=R001(tuner gain signal is wall rate under a scaled clock by design — see docstring)
+            now_w = host_now()
             dt = (now_w - last_w) if self.clock.scale > 0 else (t - last_t)
             rate = (b - last_b) / max(dt, 1e-9)
             last_t, last_b, last_w = t, b, now_w

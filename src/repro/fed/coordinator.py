@@ -49,7 +49,7 @@ import threading
 from dataclasses import dataclass, field
 
 from ..catalog import hint_bytes
-from ..core.clock import charge_to, wall_now
+from ..core.clock import charge_to, host_now, wall_now
 from ..core.connector import Connector
 from ..core.perfmodel import Advisor
 from ..core.transfer import Endpoint, TransferTask
@@ -552,10 +552,11 @@ class FederatedCoordinator:
         self.metrics.placement_log.append(
             (spec.task_id, site.site_id, reason))
         now = site.manager.service.clock.virtual_elapsed
+        host = host_now()
         # charge-free adoption marker on the adopting site's tracer: the
         # traveled trace id stitches this into the task's origin timeline
         site.manager.tracer.record(
-            "adopt", "queue", now, now,
+            "adopt", "queue", now, now, host_t0=host, host_t1=host,
             trace_id=spec.trace_id or task.trace_id,
             task_id=spec.task_id, site=site.site_id, reason=reason)
         self.bus.publish("placed", task_id=spec.task_id,
@@ -655,6 +656,7 @@ class FederatedCoordinator:
             self._precheck_adoption(task_id, origin_id, to_site)
         with charge_to(self.charge_owner):
             h0 = origin.manager.service.clock.virtual_elapsed
+            h0_host = host_now()
             payload = self._drain_export(origin, task_id, timeout)
             if payload is None:
                 return None
@@ -687,6 +689,7 @@ class FederatedCoordinator:
                 origin.manager.tracer.record(
                     "handoff", "queue", h0,
                     origin.manager.service.clock.virtual_elapsed,
+                    host_t0=h0_host, host_t1=host_now(),
                     trace_id=spec.trace_id, task_id=task_id,
                     origin=origin_id, to=site.site_id)
         return task

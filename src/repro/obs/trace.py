@@ -1,8 +1,12 @@
-"""Model-time tracing riding the charge-attribution clock.
+"""Tracing riding the charge-attribution clock, with host-time stamps.
 
 A :class:`Tracer` produces **spans** — named, categorized windows of a
-task's timeline — without ever reading the wall clock.  The span
-context lives in the same thread-local slot as the charge owner
+task's timeline.  Each span carries two times.  Its *model* time is
+charged by the clock (below).  Its *host* time is two stamps of
+:func:`repro.core.clock.host_now`, taken as the span opens and closes
+and kept in one process-wide bounded ring, the **host record**
+(:func:`host_spans`), which outlives the tracer that made the spans.
+The span context lives in the same thread-local slot as the charge owner
 (:mod:`repro.core.clock`), travels across worker/sender/pool threads
 through ``bind_charge_owner``, and is charged by ``Clock.sleep`` itself:
 every model-second a thread sleeps lands on the innermost span open on
@@ -21,9 +25,14 @@ Two export formats:
   advance the shared virtual clock, so start offsets depend on thread
   interleaving even when every per-task quantity is exact.
 * :meth:`Tracer.export_chrome` — Chrome trace-event JSON (``ph: "X"``
-  complete events over virtual microseconds), loadable in Perfetto /
-  ``chrome://tracing`` for a visual timeline.  Interleaving-dependent by
-  construction; no byte-stability claim.
+  complete events over host microseconds, the virtual start in
+  ``args``), loadable in Perfetto / ``chrome://tracing`` for a visual
+  timeline.  Interleaving-dependent by construction; no byte-stability
+  claim.
+
+While a JAX profiler trace is running, each span also enters a
+``jax.profiler.TraceAnnotation`` of its name, so the program's spans
+appear on the profiler's host plane beside the device's operations.
 
 Span discipline: ``Tracer.span(...)`` may only be used as a ``with``
 context manager (lint rule R006) — a leaked open span would swallow
@@ -32,12 +41,15 @@ every later charge on its thread and corrupt the time-budget sum.
 
 from __future__ import annotations
 
+import functools
 import json
+import sys
 import threading
 from collections import deque
+from typing import NamedTuple
 
 from ..core.clock import (_swap_trace_context, current_trace_context,
-                          trace_context)
+                          host_now, trace_context)
 
 #: span categories with a fixed place in ``TaskStats.time_budget()``;
 #: spans may use other categories, but these are the vocabulary the
@@ -46,11 +58,113 @@ CATEGORIES = ("startup", "overhead", "wire", "integrity", "backoff",
               "replica", "session", "queue", "other")
 
 
+# --------------------------------------------------------------------------
+# the host record
+# --------------------------------------------------------------------------
+class HostSpan(NamedTuple):
+    """One closed span on the host clock (``host_now`` seconds)."""
+
+    name: str
+    category: str
+    t0: float
+    t1: float
+    thread: int
+
+
+class HostSpans(NamedTuple):
+    """What :func:`host_spans` returns: the spans the ring holds, in
+    close order; how many older ones it dropped; and the latest host
+    time any dropped span ended (``-inf`` when none was dropped)."""
+
+    spans: list
+    dropped: int
+    dropped_until: float = float("-inf")
+
+    def whole_since(self, t: float) -> bool:
+        """True when every span that ended after host time ``t`` is
+        still held: a window opening at ``t`` lost nothing."""
+        return self.dropped_until <= t
+
+
+class _HostRing:
+    """Bounded ring of ``(name, category, host_t0, host_t1, thread)``
+    with an exact ``dropped`` count and the latest end of a dropped
+    span.  One per process: every enabled :class:`Tracer` appends to
+    it, so readers that run after a tracer's owner is gone still see
+    its spans."""
+
+    #: a 51 s window of the benchmark's transfer cell closes about 92 k
+    #: spans (about 2,700 per task of 80 files of 12.5 MB, 33 per file,
+    #: most of them hasher calls), a checkpoint cycle under a thousand;
+    #: this holds the window and the checks after it more than twice
+    #: over, in about 40 MB when full
+    SIZE = 1 << 18
+
+    def __init__(self, size: int = SIZE):
+        self._spans: deque = deque(maxlen=size)
+        self._lock = threading.Lock()
+        self.recorded = 0
+        self.dropped_until = float("-inf")
+
+    def append(self, item: tuple) -> None:
+        spans = self._spans
+        with self._lock:
+            if len(spans) == spans.maxlen:
+                self.dropped_until = max(self.dropped_until, spans[0][3])
+            spans.append(item)
+            self.recorded += 1
+
+    def snapshot(self, name: str | None = None) -> HostSpans:
+        with self._lock:
+            items = list(self._spans)
+            dropped = self.recorded - len(items)
+            until = self.dropped_until
+        return HostSpans([HostSpan._make(i) for i in items
+                          if name is None or i[0] == name], dropped, until)
+
+
+_HOST = _HostRing()
+
+
+def host_spans(name: str | None = None) -> HostSpans:
+    """The process's host record: every span an enabled tracer closed
+    (and every ``Tracer.record`` given host stamps), as
+    :class:`HostSpan` in close order, only those named ``name`` when it
+    is given; plus how many spans the bounded ring has dropped since the
+    process started, and when the latest of them ended.  A reader of a
+    window opening at host time ``t`` checks ``whole_since(t)``."""
+    return _HOST.snapshot(name)
+
+
+#: ``jax.profiler.TraceAnnotation``, looked up once JAX is imported: no
+#: profiler trace can run in a process that has not imported it, so the
+#: tracer never imports JAX itself
+_TRACE_ANNOTATION = None
+
+
+def _annotation(name: str):
+    """An entered ``TraceAnnotation`` of ``name`` while a JAX profiler
+    trace is running, else None."""
+    global _TRACE_ANNOTATION
+    cls = _TRACE_ANNOTATION
+    if cls is None:
+        prof = sys.modules.get("jax.profiler")
+        if prof is None:
+            return None
+        cls = _TRACE_ANNOTATION = prof.TraceAnnotation
+    if not cls.is_enabled():
+        return None
+    ann = cls(name)
+    ann.__enter__()
+    return ann
+
+
 class Span:
     """One traced window.  ``self_seconds`` is the model time charged by
     the owning thread while this span was its innermost — the
-    deterministic quantity; ``t0``/``t1`` are global virtual timestamps
-    kept for the Chrome export only.
+    deterministic quantity; ``t0``/``t1`` are global virtual timestamps;
+    ``host_t0``/``host_t1`` are ``host_now`` seconds (None for a
+    retroactive window recorded without them).
 
     A span is its own ``with`` guard AND its own thread-local trace
     context: entering swaps it into the clock's attribution slot,
@@ -61,8 +175,8 @@ class Span:
     fleet CPU."""
 
     __slots__ = ("tracer", "trace_id", "task_id", "name", "category",
-                 "attrs", "t0", "t1", "self_seconds", "thread", "_prev",
-                 "_entered")
+                 "attrs", "t0", "t1", "host_t0", "host_t1",
+                 "self_seconds", "thread", "_prev", "_entered", "_ann")
 
     #: duck-type marker for ``Clock.sleep``-compatible contexts: both
     #: Span and the root _SpanCtx expose ``span``/``charge``
@@ -76,10 +190,13 @@ class Span:
         self.attrs = attrs
         self.t0 = t0
         self.t1 = None
+        self.host_t0 = None
+        self.host_t1 = None
         self.self_seconds = 0.0
         self.thread = thread
         self._prev = None
         self._entered = False
+        self._ann = None
 
     @property
     def span(self):
@@ -104,10 +221,16 @@ class Span:
         self.t0 = self.tracer._now()
         self._prev = _swap_trace_context(self)
         self._entered = True
+        self.host_t0 = host_now()
+        self._ann = _annotation(self.name)
         return self
 
     def __exit__(self, *exc):
         if self._entered:
+            if self._ann is not None:
+                self._ann.__exit__(None, None, None)
+                self._ann = None
+            self.host_t1 = host_now()
             self._entered = False
             _swap_trace_context(self._prev)
             self.t1 = self.tracer._now()
@@ -165,8 +288,9 @@ class Tracer:
     """Fleet-wide span collector.  Thread-safe; bounded (``max_spans``
     ring with an exact ``spans_dropped`` counter, mirroring the
     StatusBus subscriber discipline).  ``clock`` is any object with a
-    ``virtual_elapsed`` attribute — virtual timestamps only, never wall
-    time."""
+    ``virtual_elapsed`` attribute, read for the virtual timestamps; host
+    stamps come from ``host_now`` and also go to the process's host
+    record (:func:`host_spans`)."""
 
     MAX_SPANS = 65536
 
@@ -193,29 +317,42 @@ class Tracer:
         self.binds += 1
         return trace_context(_SpanCtx(self, trace_id, task_id))
 
-    def span(self, name: str, category: str = "other", **attrs):
+    def span(self, name: str, category: str | None = "other", **attrs):
         """Open a span; ``with`` context manager ONLY (lint R006).
         Outside any tracer binding (no task context on this thread)
-        there is nothing to attach to, so the no-op guard comes back."""
+        there is nothing to attach to, so the no-op guard comes back.
+        ``category=None`` takes the enclosing span's category (``other``
+        at the binding's root): an instrumentation span opened inside a
+        charging one then leaves the time budget as it was."""
+        return self._open(current_trace_context(), name, category, attrs)
+
+    def _open(self, parent, name: str, category: str | None,
+              attrs: dict):
         if not self.enabled:
             return _NULL_CM
-        parent = current_trace_context()
         if parent is None or not isinstance(parent, (Span, _SpanCtx)):
             return _NULL_CM
+        if category is None:
+            category = getattr(parent, "category", "other")
         return Span(self, parent.trace_id, parent.task_id, name,
                     category, attrs, 0.0, 0)
 
     def record(self, name: str, category: str, t0: float, t1: float,
-               trace_id: str = "", task_id: str = "", **attrs) -> None:
+               trace_id: str = "", task_id: str = "",
+               host_t0: float | None = None, host_t1: float | None = None,
+               **attrs) -> None:
         """Record a retroactive window (queue wait, breaker state
         window, federation handoff) that was observed, not slept
         through: it appears in exports but charges nothing to the
-        time-budget tallies."""
+        time-budget tallies.  ``t0``/``t1`` are virtual; with
+        ``host_t0``/``host_t1`` (``host_now`` seconds) the window also
+        joins the host record, and without them it stays out of it."""
         if not self.enabled:
             return
         span = Span(self, trace_id, task_id, name, category, attrs,
-                    t0, 0)
+                    t0, threading.get_ident())
         span.t1 = t1
+        span.host_t0, span.host_t1 = host_t0, host_t1
         self._record_span(span)
 
     # ---- charge plumbing -------------------------------------------------
@@ -234,6 +371,9 @@ class Tracer:
                 self.spans_dropped += 1
             self._spans.append(span)
             self.spans_recorded += 1
+        if span.host_t0 is not None and span.host_t1 is not None:
+            _HOST.append((span.name, span.category, span.host_t0,
+                          span.host_t1, span.thread))
 
     # ---- tallies ---------------------------------------------------------
     def category_seconds(self, task_id: str) -> dict[str, float]:
@@ -270,23 +410,27 @@ class Tracer:
         return len(spans)
 
     def export_chrome(self, path: str) -> int:
-        """Chrome trace-event JSON over *virtual* microseconds — open it
-        in Perfetto (ui.perfetto.dev) or ``chrome://tracing``.  Complete
-        (``ph: "X"``) events; pid = task, tid = a stable per-thread
-        index in first-seen order."""
-        spans = self.spans()
+        """Chrome trace-event JSON over *host* microseconds (``host_now``)
+        — open it in Perfetto (ui.perfetto.dev) or ``chrome://tracing``.
+        Complete (``ph: "X"``) events; pid = task, tid = a stable
+        per-thread index in first-seen order; ``args`` keeps the virtual
+        start (``virtual_t0``) and the model seconds charged.  A window
+        recorded without host stamps has no place on it and is left
+        out."""
+        spans = [s for s in self.spans()
+                 if s.host_t0 is not None and s.host_t1 is not None]
         tids: dict[int, int] = {}
         events = []
         for s in spans:
             tid = tids.setdefault(s.thread, len(tids))
-            t1 = s.t1 if s.t1 is not None else s.t0
             events.append({
                 "name": s.name, "cat": s.category, "ph": "X",
-                "ts": round(s.t0 * 1e6, 3),
-                "dur": round(max(0.0, t1 - s.t0) * 1e6, 3),
+                "ts": round(s.host_t0 * 1e6, 3),
+                "dur": round(max(0.0, s.host_t1 - s.host_t0) * 1e6, 3),
                 "pid": s.task_id or s.trace_id or "fleet",
                 "tid": tid,
                 "args": dict(s.attrs, trace_id=s.trace_id,
+                             virtual_t0=s.t0,
                              self_seconds=round(s.self_seconds, 9)),
             })
         with open(path, "w") as fh:
@@ -298,3 +442,27 @@ class Tracer:
 #: shared disabled tracer: the default for a bare ``TransferService``
 #: so un-instrumented construction paths pay (almost) nothing
 NULL_TRACER = Tracer(enabled=False)
+
+
+def thread_span(name: str, category: str | None = None, **attrs):
+    """``Tracer.span`` on whichever tracer is bound on this thread (the
+    no-op guard where none is) — for code that holds no tracer of its
+    own, such as connectors and hashers.  ``with`` only (lint R006)."""
+    ctx = current_trace_context()
+    if ctx is None:
+        return _NULL_CM
+    return ctx.tracer._open(ctx, name, category, attrs)
+
+
+def spanned(name: str, category: str | None = None):
+    """Decorator: every call of the function runs under
+    ``thread_span(name, category)``."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def call(*args, **kwargs):
+            if current_trace_context() is None:
+                return fn(*args, **kwargs)
+            with thread_span(name, category):
+                return fn(*args, **kwargs)
+        return call
+    return wrap

@@ -54,6 +54,17 @@ def make_site(tmp_path, name, clock, tenants=("alice",), advisor=None,
                            clock=clock, site_id=name)
 
 
+def wait_landed(task, nbytes, timeout=30):
+    """The blocks _HoldSrc let through before it engaged are still in
+    flight on the receive side, and a pause stops the receiver at block
+    granularity: wait until ``nbytes`` landed durable before pausing
+    (same sequencing as ScenarioRunner.run_federated)."""
+    deadline = time.monotonic() + timeout
+    while task.stats.bytes_done < nbytes and time.monotonic() < deadline:
+        time.sleep(0.002)
+    assert task.stats.bytes_done >= nbytes
+
+
 def seed_memory(files):
     conn = MemoryConnector()
     for name, payload in files.items():
@@ -225,6 +236,7 @@ def test_manager_export_import_paused_task(tmp_path):
                           Endpoint(dst_conn, "out", "dst-ep"), opts,
                           task_id="trav-1", tenant="alice")
     assert src_conn.engaged.wait(30)
+    wait_landed(task_a, 256 * KB)
     mgr_a.pause("trav-1")
     src_conn.release()
     deadline = time.monotonic() + 30
@@ -381,6 +393,7 @@ def test_checksum_fold_on_pause_resume_no_source_reread(tmp_path):
                       Endpoint(dst_conn, "out", "dst-ep"), opts,
                       task_id="fold-1")
     assert src_conn.engaged.wait(30)
+    wait_landed(task, 512 * KB)
     mgr.pause("fold-1")
     src_conn.release()
     assert task.wait_idle(30)
@@ -437,6 +450,7 @@ def test_checksum_fold_travels_across_handoff(tmp_path):
         n_files=1, nbytes=len(payload))
     task_a = coord.submit(spec.to_json())
     assert src_conn.engaged.wait(30)
+    wait_landed(task_a, 256 * KB)
 
     out: list = []
     mover = threading.Thread(
@@ -489,6 +503,7 @@ def test_checksum_fold_discarded_when_source_changes_under_pause(tmp_path):
                       Endpoint(dst_conn, "out", "dst-ep"), opts,
                       task_id="mut-1")
     assert src_conn.engaged.wait(30)
+    wait_landed(task, 256 * KB)
     mgr.pause("mut-1")
     src_conn.release()
     assert task.wait_idle(30)
@@ -629,6 +644,51 @@ def test_handoff_without_adoptable_peer_never_strands_the_task(tmp_path):
     assert task.wait(30)
     assert task.status == task.SUCCEEDED
     assert dst_conn.store.get("out/big.bin") == payload
+    coord.shutdown(wait=False)
+
+
+def test_handoff_and_adoption_stay_on_the_chrome_timeline(tmp_path):
+    """The coordinator's charge-free ``handoff`` and ``adopt`` windows
+    carry host stamps, so the sites' Chrome exports keep them."""
+    clock = Clock(scale=0.0)
+    payload = os.urandom(1 * MB)
+    src_conn = _HoldSrc(seed_memory({"data/big.bin": payload}))
+    src_conn.arm_hold(["data/"], 128 * KB)
+    dst_conn = MemoryConnector()
+    eps = {"src-ep": src_conn, "dst-ep": dst_conn}
+    coord = FederatedCoordinator(placement="owner")
+    coord.register_site("a", make_site(tmp_path, "a", clock), eps,
+                        owns={"src-ep", "dst-ep"})
+    coord.register_site("b", make_site(tmp_path, "b", clock), eps,
+                        owns=set())
+    spec = TransferSpec.new(
+        "chrome-1", "src-ep", "data", "dst-ep", "out", tenant="alice",
+        options=TransferOptions(startup_cost=0.0, concurrency=1,
+                                parallelism=1, blocksize=64 * KB,
+                                coalesce_threshold=0),
+        n_files=1, nbytes=len(payload))
+    task = coord.submit(spec.to_json())
+    assert src_conn.engaged.wait(30)
+    mgr_a = coord.sites()["a"].manager
+    mgr_a.pause("chrome-1")
+    src_conn.release()
+    assert task.wait_idle(30)
+    deadline = time.monotonic() + 30
+    while task.status != task.PAUSED and time.monotonic() < deadline:
+        time.sleep(0.005)
+    assert task.status == task.PAUSED
+
+    moved = coord.handoff("chrome-1", to_site="b")
+    assert moved is not None and moved.wait(30)
+    assert moved.status == moved.SUCCEEDED
+    names = {}
+    for site in ("a", "b"):
+        p = str(tmp_path / f"chrome-{site}.json")
+        coord.sites()[site].manager.tracer.export_chrome(p)
+        with open(p) as fh:
+            names[site] = {ev["name"] for ev in json.load(fh)["traceEvents"]}
+    assert {"adopt", "handoff"} <= names["a"]
+    assert "adopt" in names["b"]
     coord.shutdown(wait=False)
 
 

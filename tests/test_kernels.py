@@ -188,6 +188,25 @@ def test_checksum_word_view_matches_digest_ref(shape, dtype):
     assert checksum_digest(jnp.asarray(x), use_pallas=False) == want
 
 
+@pytest.mark.parametrize("n_bytes", [0, 3, 20, 21, 40, 103])
+def test_digest_ref_slices_match_the_definition(monkeypatch, n_bytes):
+    """digest_ref folds its words in slices of ``CHUNK``: with slices of
+    5 words, every length across slice ends gives the definition's
+    ``a = sum w_i``, ``b = sum i * w_i`` (mod 2**32)."""
+    from repro.kernels.checksum import ref
+    monkeypatch.setattr(ref, "CHUNK", 5)
+    data = np.random.default_rng(n_bytes).integers(
+        0, 256, n_bytes, dtype=np.uint8).tobytes()
+    padded = data + b"\0" * ((-n_bytes) % 4)
+    words = [int.from_bytes(padded[k:k + 4], "little")
+             for k in range(0, len(padded), 4)]
+    a = sum(words) % (1 << 32)
+    b = sum(i * w for i, w in enumerate(words, 1)) % (1 << 32)
+    assert ref.digest_ref(data) == f"{b:08x}{a:08x}"
+    signed = np.frombuffer(padded, dtype="<i4")
+    assert ref.lanesum32_ref(signed) == (a, b)
+
+
 @settings(max_examples=30, deadline=None)
 @given(st.binary(min_size=0, max_size=3000))
 def test_lanesum32_stream_matches_ref(data):

@@ -663,6 +663,34 @@ def test_observation_history_is_bounded(tmp_path):
     mgr.shutdown()
 
 
+class _BrokenHistogram:
+    def observe(self, *args, **kwargs):
+        raise RuntimeError("accounting fault")
+
+
+def test_task_completes_when_its_accounting_raises(tmp_path, monkeypatch):
+    """The manager completes a task after filing its run; a fault in
+    that filing still releases whoever waits on the task (and still
+    reaches the worker thread's exception hook)."""
+    raised = []
+    caught = threading.Event()
+
+    def hook(args):
+        raised.append(args.exc_value)
+        caught.set()
+    monkeypatch.setattr(threading, "excepthook", hook)
+    mgr = make_manager(tmp_path, max_workers=1)
+    mgr._task_seconds = _BrokenHistogram()
+    src = seeded_posix(tmp_path, {"d/f.bin": os.urandom(256)})
+    task = mgr.submit(Endpoint(src, "d"), Endpoint(MemoryConnector(), "o"),
+                      TransferOptions(startup_cost=0.0), task_id="acct")
+    assert task.wait(30)
+    assert task.status == task.SUCCEEDED
+    assert caught.wait(30)
+    assert [str(e) for e in raised] == ["accounting fault"]
+    mgr.shutdown()
+
+
 def test_refit_convergence_under_multitenant_chaos(tmp_path):
     """Acceptance: a multi-tenant fleet under fault injection still
     shrinks its median prediction error once the online refit loop has

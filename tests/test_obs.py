@@ -22,12 +22,13 @@ import pytest
 from repro.connectors import MemoryConnector
 from repro.core import (CredentialStore, Endpoint, FaultSchedule,
                         TransferManager, TransferOptions)
-from repro.core.clock import Clock, bind_charge_owner, charge_to
+from repro.core.clock import Clock, bind_charge_owner, charge_to, host_now
 from repro.core.transfer import TransferTask
 from repro.fed import TransferSpec
 from repro.lint.engine import run_lint
 from repro.obs import (CATEGORIES, DEFAULT_BUCKETS, MetricsRegistry,
-                       NULL_TRACER, Tracer)
+                       NULL_TRACER, Tracer, host_spans, thread_span)
+from repro.obs import trace as obs_trace
 from repro.sim import ScenarioRunner
 
 KB = 1024
@@ -45,6 +46,18 @@ def make_manager(tmp_path, **kw):
     return TransferManager(credential_store=CredentialStore(),
                            marker_root=os.path.join(str(tmp_path), "markers"),
                            **kw)
+
+
+class _GatedMemory(MemoryConnector):
+    """A memory store whose sessions open only once ``gate`` is set."""
+
+    def __init__(self, gate):
+        super().__init__()
+        self.gate = gate
+
+    def start(self, credential):
+        assert self.gate.wait(30)
+        return super().start(credential)
 
 
 def seed_memory(files):
@@ -164,6 +177,134 @@ def test_charge_crosses_threads_via_bind_charge_owner():
 
 
 # --------------------------------------------------------------------------
+# host time: stamps, the process-wide host record, the profiler
+# --------------------------------------------------------------------------
+def test_span_host_stamps_lie_between_perf_counter_reads():
+    tracer = Tracer(clock=Clock(scale=0.0))
+    with tracer.bind("trace-h", "th"):
+        before = host_now()
+        with tracer.span("host-stamped", "wire"):
+            pass
+        after = host_now()
+    span = tracer.spans()[-1]
+    assert before <= span.host_t0 <= span.host_t1 <= after
+    rec = host_spans("host-stamped").spans[-1]
+    assert (rec.category, rec.t0, rec.t1) == ("wire", span.host_t0,
+                                              span.host_t1)
+    assert rec.thread == threading.get_ident()
+
+
+def test_host_ring_bounded_with_exact_drop_count(monkeypatch):
+    monkeypatch.setattr(obs_trace, "_HOST", obs_trace._HostRing(4))
+    tracer = Tracer(clock=Clock(scale=0.0))
+    with tracer.bind("trace-r", "tr"):
+        for i in range(10):
+            with tracer.span(f"r{i}"):
+                pass
+    record = host_spans()
+    assert [s.name for s in record.spans] == ["r6", "r7", "r8", "r9"]
+    assert record.dropped == 6
+    assert [s.name for s in host_spans("r9").spans] == ["r9"]
+    assert host_spans("r9").dropped == 6
+    # the latest dropped span, r5, ended before r6 opened: a window
+    # opening at r6 lost nothing, one opening at r5 did
+    r5_end = record.dropped_until
+    assert r5_end <= record.spans[0].t0
+    assert record.whole_since(record.spans[0].t0)
+    assert not record.whole_since(r5_end - 1e-9)
+
+
+def test_host_record_outlives_its_tracer(monkeypatch):
+    monkeypatch.setattr(obs_trace, "_HOST", obs_trace._HostRing(16))
+    tracer = Tracer(clock=Clock(scale=0.0))
+    with tracer.bind("trace-o", "to"), tracer.span("kept"):
+        pass
+    del tracer
+    assert [s.name for s in host_spans().spans] == ["kept"]
+
+
+def test_disabled_tracer_and_unbound_threads_record_no_host_spans(
+        monkeypatch):
+    monkeypatch.setattr(obs_trace, "_HOST", obs_trace._HostRing(16))
+    off = Tracer(clock=Clock(scale=0.0), enabled=False)
+    with off.bind("trace-d", "td"), off.span("off"):
+        pass
+    off.record("off-window", "queue", 0.0, 1.0, host_t0=1.0, host_t1=2.0)
+    on = Tracer(clock=Clock(scale=0.0))
+    with on.span("unbound"):  # no binding on this thread
+        pass
+    with thread_span("unbound-thread"):
+        pass
+
+    def other_thread():
+        with on.span("elsewhere"):
+            pass
+    with on.bind("trace-b", "tb"):
+        th = threading.Thread(target=other_thread)  # not bound across
+        th.start()
+        th.join(10)
+    assert not th.is_alive()
+    record = host_spans()
+    assert (record.spans, record.dropped) == ([], 0)
+    assert on.spans_recorded == 0
+
+
+def test_record_joins_host_record_only_with_host_stamps(monkeypatch):
+    monkeypatch.setattr(obs_trace, "_HOST", obs_trace._HostRing(16))
+    tracer = Tracer(clock=Clock(scale=0.0))
+    tracer.record("no-host", "queue", 1.0, 2.0, task_id="t1")
+    tracer.record("with-host", "queue", 1.0, 2.0, task_id="t1",
+                  host_t0=10.0, host_t1=10.5, tenant="alice")
+    assert tracer.spans_recorded == 2
+    assert [(s.name, s.category, s.t0, s.t1)
+            for s in host_spans().spans] == [("with-host", "queue", 10.0,
+                                              10.5)]
+    with_host = tracer.spans()[-1]
+    assert (with_host.t0, with_host.t1) == (1.0, 2.0)
+    assert with_host.attrs == {"tenant": "alice"}
+    # still charge-free, with or without host stamps
+    assert tracer.category_seconds("t1") == {}
+
+
+def test_inherited_category_leaves_the_budget_unchanged():
+    clock = Clock(scale=0.0)
+    tracer = Tracer(clock=clock)
+    with tracer.bind("trace-1", "t1"), charge_to("t1"):
+        with tracer.span("send", "wire"):
+            with thread_span("connector.io"):
+                clock.sleep(0.5)
+        with thread_span("connector.io"):  # at the root: "other"
+            clock.sleep(0.25)
+    assert tracer.category_seconds("t1") == {
+        "wire": pytest.approx(0.5), "other": pytest.approx(0.25)}
+    io = [s for s in tracer.spans() if s.name == "connector.io"]
+    assert [s.category for s in io] == ["wire", "other"]
+
+
+def test_span_annotates_a_running_profiler_trace(tmp_path):
+    jax = pytest.importorskip("jax")
+    from jax import profiler
+
+    tracer = Tracer(clock=Clock(scale=0.0))
+    with tracer.bind("trace-p", "tp"):
+        with tracer.span("obs.before-trace"):
+            pass
+        profiler.start_trace(str(tmp_path))
+        try:
+            with tracer.span("obs.in-trace", "wire"):
+                jax.numpy.zeros(4).block_until_ready()
+        finally:
+            profiler.stop_trace()
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    data = profiler.ProfileData.from_file(str(path))
+    host = {ev.name for plane in data.planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events}
+    assert "obs.in-trace" in host
+    assert "obs.before-trace" not in host
+
+
+# --------------------------------------------------------------------------
 # exports
 # --------------------------------------------------------------------------
 def _trace_some(tracer, clock):
@@ -174,7 +315,8 @@ def _trace_some(tracer, clock):
             with tracer.span("verify", "integrity", path="a.bin"):
                 clock.sleep(0.125)
     tracer.record("queue-wait", "queue", 0.0, 0.25,
-                  trace_id="trace-1", task_id="t1", tenant="alice")
+                  trace_id="trace-1", task_id="t1", tenant="alice",
+                  host_t0=host_now() - 0.25, host_t1=host_now())
 
 
 def test_jsonl_export_sorted_and_stable(tmp_path):
@@ -211,8 +353,39 @@ def test_chrome_export_is_loadable_trace_event_json(tmp_path):
         assert ev["ph"] == "X"
         assert ev["ts"] >= 0 and ev["dur"] >= 0
         assert ev["pid"] == "t1" or ev["pid"] == "trace-1"
+    # the timeline is host time; the model side rides in args
     send = next(ev for ev in events if ev["name"] == "send")
-    assert send["dur"] == pytest.approx(0.5e6)
+    assert send["args"]["virtual_t0"] == 0.0
+    assert send["args"]["self_seconds"] == pytest.approx(0.5)
+    verify = next(ev for ev in events if ev["name"] == "verify")
+    assert verify["args"]["virtual_t0"] == pytest.approx(0.5)
+    assert verify["ts"] >= send["ts"] + send["dur"]
+    wait = next(ev for ev in events if ev["name"] == "queue-wait")
+    assert wait["dur"] == pytest.approx(0.25e6, rel=1e-3)
+    # a window recorded without host stamps has no place on it
+    tracer.record("handoff", "queue", 0.0, 1.0, task_id="t1")
+    assert tracer.export_chrome(p) == n
+
+
+def test_breaker_windows_stay_on_the_chrome_timeline(tmp_path):
+    from repro.core.health import EndpointHealth, HealthConfig
+    clock = Clock(scale=0.0)
+    tracer = Tracer(clock=clock)
+    before = host_now()
+    health = EndpointHealth(HealthConfig(min_samples=1,
+                                         error_threshold=0.3),
+                            clock=clock, tracer=tracer)
+    health.record_failure("ep")  # closed -> open
+    assert health.state("ep") == "open"
+    after = host_now()
+    p = str(tmp_path / "trace.json")
+    assert tracer.export_chrome(p) == 1
+    with open(p) as fh:
+        (ev,) = json.load(fh)["traceEvents"]
+    assert ev["name"] == "breaker-closed" and ev["args"]["to"] == "open"
+    # the closed window runs from the endpoint's first sighting
+    assert before * 1e6 <= ev["ts"] <= ev["ts"] + ev["dur"] \
+        <= after * 1e6 + 1
 
 
 # --------------------------------------------------------------------------
@@ -400,9 +573,12 @@ def test_trace_id_survives_export_import(tmp_path):
     dst = MemoryConnector()
     a = make_manager(tmp_path / "a", max_workers=1)
     b = make_manager(tmp_path / "b", max_workers=1)
-    # keep it queued on a busy site so export_state can take it
-    blocker = a.submit(Endpoint(seed_memory({"t/f.bin": b"y" * KB}), "t",
-                                "bsrc"),
+    # keep it queued on a busy site so export_state can take it: the
+    # blocker holds the site's one worker until the handoff is done
+    gate = threading.Event()
+    blocker_src = _GatedMemory(gate)
+    blocker_src.store.put("t/f.bin", b"y" * KB)
+    blocker = a.submit(Endpoint(blocker_src, "t", "bsrc"),
                        Endpoint(MemoryConnector(), "o", "bdst"),
                        TransferOptions(startup_cost=0.0), task_id="blk")
     task = a.submit(Endpoint(src, "t0", "s0"), Endpoint(dst, "o/t0", "d0"),
@@ -415,6 +591,7 @@ def test_trace_id_survives_export_import(tmp_path):
                              Endpoint(dst, "o/t0", "d0"))
     assert adopted.trace_id == trace_id
     assert task.status == task.HANDED_OFF
+    gate.set()
     assert a.wait_all(timeout=60) and b.wait_all(timeout=60)
     assert adopted.status == adopted.SUCCEEDED
     budget = adopted.stats.time_budget()
